@@ -33,8 +33,11 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run_script(script: str, timeout: int = 560) -> dict:
     """Run a measurement script in a clean subprocess (forced device count
-    must be set before jax import); it must print one JSON line last."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(_ROOT, "src"))
+    must be set before jax import); it must print one JSON line last. The
+    child is pinned to the CPU: its devices are forced host devices, and an
+    accelerator belongs to one process at a time."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(_ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=timeout)

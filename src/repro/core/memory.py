@@ -143,9 +143,6 @@ class MachineSpec:
     memory_bytes: float = 16e9        # R
     n_processors: int = 256           # P
     bytes_per_scalar: int = 4         # Q (fp32 kernel rows)
-    hbm_gbps: float = 819.0
-    peak_tflops_bf16: float = 197.0
-    ici_gbps_per_link: float = 50.0
 
 
 def footprint_bytes(n: int, b: int, c: int, p: int, q: int = 4, *,

@@ -1,66 +1,41 @@
-"""Version-compat shims for the mesh / shard_map API surface.
-
-The pinned JAX still hosts ``shard_map`` under ``jax.experimental.shard_map``
-with a ``check_rep`` flag; newer releases moved it to ``jax.shard_map`` with
-``check_vma``. ``make_mesh`` likewise only grew ``axis_types`` recently.
-Every distributed module imports from here so one pin bump never fans out.
+"""The mesh / shard_map / compiled-program API surface the distributed modules
+share: ``shard_map`` with the ``check_vma`` flag, Auto-axis ``make_mesh``, and
+the compiled program's cost and memory analyses as plain dicts.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # pinned JAX: experimental location, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version.
-
-    The pinned JAX returns a one-element list of per-computation dicts;
-    newer releases return the dict directly.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a dict (``{}`` when the backend has
+    none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def memory_stats(compiled) -> dict:
-    """``compiled.memory_analysis()`` as the dryrun report dict.
-
-    ``peak_memory_in_bytes`` only exists on newer JAX; older releases get
-    the conservative upper bound temp + arguments + outputs instead of None.
-    """
+    """``compiled.memory_analysis()`` as the dryrun report dict."""
     mem = compiled.memory_analysis()
-    temp = getattr(mem, "temp_size_in_bytes", None)
-    args = getattr(mem, "argument_size_in_bytes", None)
-    out = getattr(mem, "output_size_in_bytes", None)
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is None and None not in (temp, args, out):
-        peak = temp + args + out
     return {
-        "bytes_per_device": temp,
-        "argument_bytes": args,
-        "output_bytes": out,
-        "peak_bytes": peak,
+        "bytes_per_device": mem.temp_size_in_bytes,
+        "argument_bytes": mem.argument_size_in_bytes,
+        "output_bytes": mem.output_size_in_bytes,
+        "peak_bytes": mem.peak_memory_in_bytes,
     }
 
 
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_shapes))
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (plain ``jax.make_mesh``
+    makes them ``Explicit``)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes),
+                         devices=devices)
 
 
 __all__ = ["shard_map", "make_mesh", "cost_analysis", "memory_stats"]

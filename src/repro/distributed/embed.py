@@ -55,7 +55,7 @@ from repro.obs import resolve as resolve_recorder
 from repro.obs import trace as obs_trace
 
 from .compat import shard_map
-from .mesh import axis_size, ghost_row_ids, row_axes_of
+from .mesh import auto_axes, axis_size, ghost_row_ids, row_axes_of
 
 Array = jax.Array
 
@@ -187,7 +187,7 @@ class DistributedEmbedKMeans:
                              "cfg.method ('rff', 'nystrom', 'sketch', "
                              "'tensorsketch'); use "
                              "DistributedMiniBatchKMeans for 'exact'")
-        self.mesh = mesh
+        mesh = self.mesh = auto_axes(mesh)
         self.cfg = cfg
         self.fmap = fmap
         # repro.obs flight recorder; hooks are host-side only. ``stage``
@@ -239,11 +239,14 @@ class DistributedEmbedKMeans:
                 # dense: the UNPADDED rows, so a data-dependent map
                 # (Nystrom landmarks) sees exactly what the inline path's
                 # raw batch gives it — ghost rows must not alter the model.
+                # A host copy, as that batch is: the map is built on one
+                # device (a Mosaic kernel cannot be partitioned by XLA).
                 sample = (CSRBatch(data=np.zeros((0,), np.float32),
                                    indices=np.zeros((0,), np.int32),
                                    indptr=np.zeros((1,), np.int32),
                                    shape=(0, sample.d))
-                          if sample.sparse else sample.x[:sample.n])
+                          if sample.sparse
+                          else np.asarray(sample.x[:sample.n]))
             m = cfg.embed_dim or approx.default_embed_dim(cfg.n_clusters)
             self.fmap = approx.make_feature_map(
                 cfg.method, jax.random.PRNGKey(cfg.seed), sample, m,
@@ -291,7 +294,10 @@ class DistributedEmbedKMeans:
             out_specs=P(self.row_axes), check_vma=False))(
                 st.x, st.wgt, pilot, whiten)
         idx = sel.gumbel_top_m(key, scores[:st.n], gids, m)
-        return nystrom_from_landmarks(jnp.take(st.x, idx, axis=0), spec)
+        # the m landmark rows, through the host: the map is built on one
+        # device (a Mosaic kernel cannot be partitioned by XLA)
+        landmarks = jax.device_get(jnp.take(st.x, idx, axis=0))
+        return nystrom_from_landmarks(jnp.asarray(landmarks), spec)
 
     # -- staging: host batch -> mesh-resident, pre-sharded -----------------
 

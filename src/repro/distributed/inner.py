@@ -416,6 +416,19 @@ def distributed_kkmeans_fit(mesh: Mesh, x: Array, landmarks: Array,
                       ghost rows that pad a non-divisible batch, so they
                       never count in the cost (default: all ones).
     """
+    if wgt is None:
+        wgt = jnp.ones((x.shape[0],), jnp.float32)
+    return DistInnerResult(*_mesh_program(x, landmarks, l_idx, diag_k, u0,
+                                          wgt, mesh=mesh, cfg=cfg))
+
+
+# One compiled program per (mesh, cfg, shapes): run eagerly, the shard_map
+# would execute op by op — every Gram-block intermediate then lands in HBM
+# unfused (a resident 35,000^2 f32 block needs 4x its size) and a fit
+# compiles hundreds of one-op programs.
+@partial(jax.jit, static_argnames=("mesh", "cfg"))
+def _mesh_program(x, landmarks, l_idx, diag_k, u0, wgt, *, mesh: Mesh,
+                  cfg: DistributedInnerConfig):
     row_axes, col_axis = cfg.row_axes, cfg.col_axis
     d_size = 1
     for a in row_axes:
@@ -441,9 +454,6 @@ def distributed_kkmeans_fit(mesh: Mesh, x: Array, landmarks: Array,
 
     rowspec = P(row_axes)
     colspec = P(col_axis) if col_axis is not None else P()
-    if wgt is None:
-        wgt = jnp.ones((x.shape[0],), jnp.float32)
-
     fn = partial(_inner_shard_fn, cfg=cfg, row_strides=row_strides,
                  d_size=d_size, m_size=m_size)
     shard_fn = shard_map(
@@ -464,6 +474,4 @@ def distributed_kkmeans_fit(mesh: Mesh, x: Array, landmarks: Array,
         out_specs=(rowspec, P(row_axes, None), P(), P(), P(), P()),
         check_vma=False,
     )
-    u, f, g, counts, t, cost = shard_fn(x, landmarks, landmarks, diag_k,
-                                        l_idx, l_idx, u0, wgt)
-    return DistInnerResult(u, f, g, counts, t, cost)
+    return shard_fn(x, landmarks, landmarks, diag_k, l_idx, l_idx, u0, wgt)

@@ -11,7 +11,7 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_test_mesh(axes: dict[str, int] | None = None) -> Mesh:
@@ -29,6 +29,15 @@ def make_test_mesh(axes: dict[str, int] | None = None) -> Mesh:
         raise ValueError(f"mesh {axes} needs {math.prod(shape)} devices, have {n}")
     from .compat import make_mesh
     return make_mesh(shape, tuple(axes.keys()))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The caller's mesh with every axis ``Auto``. The mesh runners index
+    row-sharded batches outside shard_map (``jnp.take``, ``x[:n]``), which
+    an ``Explicit`` axis — what plain ``jax.make_mesh`` builds — refuses;
+    on ``Auto`` axes the compiler inserts the gathers itself."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def axis_size(mesh: Mesh, names: tuple[str, ...] | str) -> int:

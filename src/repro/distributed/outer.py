@@ -45,7 +45,7 @@ from repro.obs import resolve as resolve_recorder
 from .compat import shard_map
 from .inner import (DistributedInnerConfig, collectives_per_iteration,
                     distributed_kkmeans_fit)
-from .mesh import ghost_row_ids
+from .mesh import auto_axes, ghost_row_ids
 
 Array = jax.Array
 
@@ -88,7 +88,7 @@ class DistributedMiniBatchKMeans:
         outside-the-loop prologue sync — never by instrumenting the traced
         body; ``inner.collectives_per_iteration`` stays as the analytic
         cross-check the audit must agree with)."""
-        self.mesh = mesh
+        mesh = self.mesh = auto_axes(mesh)
         self.cfg = cfg
         self.rec = resolve_recorder(recorder)
         row_axes = tuple(n for n in mesh.axis_names if n != "model")

@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import gpu_compiler_params
-from .compat import CompilerParams
 from .kernel_matrix import _epilogue
 
 
@@ -203,8 +202,8 @@ def assign_fused_pallas(x, landmarks, xsq, lsq, h_norm, g, *,
         in_specs=[
             # x/landmarks stay HBM-resident (ANY): the kernel streams their
             # feature chunks through the double-buffered VMEM slots itself.
-            pl.BlockSpec(memory_space=pltpu.ANY),             # x
-            pl.BlockSpec(memory_space=pltpu.ANY),             # landmarks
+            pl.BlockSpec(memory_space=pl.ANY),                # x
+            pl.BlockSpec(memory_space=pl.ANY),                # landmarks
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),       # xsq
             pl.BlockSpec((bl, 1), lambda i, j: (j, 0)),       # lsq
             pl.BlockSpec((bl, cp), lambda i, j: (j, 0)),      # h_norm
@@ -223,7 +222,7 @@ def assign_fused_pallas(x, landmarks, xsq, lsq, h_norm, g, *,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((bm, cp), jnp.float32),    # f accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

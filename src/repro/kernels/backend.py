@@ -20,7 +20,7 @@ Every kernel module in this package ships two bodies behind one wrapper:
 
 ``kernel_backend("auto")`` resolves the seam at trace time from
 ``jax.default_backend()`` — which ``launch/env.py``'s ``--platform`` flag
-pins before the first jax import (snippet-style ``set_platform`` idiom).
+pins through ``JAX_PLATFORMS`` before the first jax import.
 CPU resolves to the TPU body in interpret mode: it is the reference
 lowering and the one the oracles pin tightest.
 """

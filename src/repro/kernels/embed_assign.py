@@ -41,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import gpu_compiler_params
-from .compat import CompilerParams
 from .kernel_matrix import _epilogue
 
 
@@ -178,7 +177,7 @@ def embed_assign_pallas(x, w, xsq, aux, v, csq, *,
             pltpu.VMEM((bm, bme), jnp.float32),
             pltpu.VMEM((bm, cp), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

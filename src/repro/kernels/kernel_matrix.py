@@ -25,7 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import gpu_compiler_params
-from .compat import CompilerParams
 
 
 def _epilogue(kind: str, acc, xsq, ysq, *, gamma, coef0, degree):
@@ -122,7 +121,7 @@ def kernel_matrix_pallas(x, y, xsq, ysq, *, kind: str = "rbf",
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

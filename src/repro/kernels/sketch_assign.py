@@ -44,7 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import gpu_compiler_params
-from .compat import CompilerParams
 
 
 def _kernel(x_ref, h_ref, sign_ref, v_ref, csq_ref,
@@ -64,12 +63,14 @@ def _kernel(x_ref, h_ref, sign_ref, v_ref, csq_ref,
     xt = x_ref[...]                                  # [bm, bd] tile dtype
     h = h_ref[...]                                   # [bd, 1] int32
     # sign table storage is a precision-policy choice (int8 under bf16 —
-    # ±1 is exact in every float format); the in-VMEM sketch tile is built
-    # in the x tile dtype so the MXU contraction sees matched operands.
-    sign = sign_ref[...].astype(xt.dtype)            # [bd, 1]
+    # ±1 is exact in every float format). The sketch tile is selected in
+    # f32 and cast once to the x tile dtype, so the MXU contraction sees
+    # matched operands: Mosaic cannot relayout the [bd, bme] mask for a
+    # packed (bf16) select.
+    sign = sign_ref[...].astype(jnp.float32)         # [bd, 1]
     bd = h.shape[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (bd, bme), 1) + j * bme
-    s = jnp.where(h == lane, sign, jnp.zeros((), xt.dtype))
+    s = jnp.where(h == lane, sign, 0.0).astype(xt.dtype)
     acc_a_ref[...] += jax.lax.dot_general(
         xt, s, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -170,7 +171,7 @@ def sketch_assign_pallas(x, h, sign, v, csq, *,
             pltpu.VMEM((bm, bme), jnp.float32),
             pltpu.VMEM((bm, cp), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -16,7 +16,7 @@ from __future__ import annotations
 import argparse
 import time
 
-# XLA_FLAGS / JAX_PLATFORM_NAME must be staged BEFORE the first jax
+# XLA_FLAGS / JAX_PLATFORMS must be staged BEFORE the first jax
 # import: the latency-hiding scheduler that overlaps the s-step loop's one
 # fused collective per sync with the next Gram panel is a compile-time,
 # process-level switch, and the --platform pin (which also selects the
@@ -84,7 +84,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     mesh = build_mesh(args.mesh)
-    n_proc = len(jax.devices())
+    n_proc = mesh.size
 
     # -- data (synthetic stand-in for the MD/UCI streams; DESIGN.md §8.5)
     x, y = make_blobs(args.n, args.d, args.clusters, sep=8.0,

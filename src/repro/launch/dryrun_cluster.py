@@ -241,7 +241,10 @@ def smoke_driver(args) -> int:
     with socket.socket() as s:   # grab a free coordinator port
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
+    # the ranks run on forced host devices: pin them to the CPU so none of
+    # them contends for an accelerator this host may have
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                REPRO_SMOKE_DEVICES="2",
                REPRO_SMOKE_NPROCS=str(args.smoke_mp),
                REPRO_SMOKE_COORD=f"localhost:{port}")

@@ -1,79 +1,57 @@
 """Process-level JAX/XLA environment setup for the launchers.
 
-The s-step inner loop (``repro.distributed.inner``) is built so its one
-fused psum per sync can overlap the next Gram panel: the tiled engine
-double-buffers panel builds against contractions, and the loop body has no
-host sync the scheduler must serialize around. Whether XLA actually hides
-the collective behind compute is decided at COMPILE time by the
-latency-hiding scheduler, which is switched on with process-level flags
-that must be in ``XLA_FLAGS`` before the first ``import jax`` touches the
-backend. This module owns that dance:
+Everything here must happen before the first ``import jax``: XLA parses
+``XLA_FLAGS`` once at backend init, and JAX reads ``JAX_PLATFORMS`` and
+``JAX_COMPILATION_CACHE_DIR`` when it is imported. This module owns that:
 
   * ``configure(...)`` — call it FIRST (before importing anything that
-    imports jax). It merges the GPU latency-hiding/async-collective flag
-    set into ``XLA_FLAGS`` without clobbering flags the caller (or a test
-    harness — ``--xla_force_host_platform_device_count``) already set,
-    and (``platform=``) pins ``JAX_PLATFORM_NAME`` — the backend pin that
-    also selects the kernel lowering (kernels/backend.py follows
-    ``jax.default_backend()``: Mosaic on tpu, Triton on gpu, interpret
-    elsewhere).
+    imports jax). It
+      - pins the backend through ``JAX_PLATFORMS`` (``platform=``). JAX then
+        fails at start-up when that platform has no device, instead of
+        falling back to the CPU; the pin also selects the kernel lowering
+        (kernels/backend.py follows ``jax.default_backend()``: Mosaic on
+        tpu, Triton on gpu, interpret elsewhere);
+      - points JAX's persistent compilation cache at ``<checkout>/.jax_cache``
+        unless ``JAX_COMPILATION_CACHE_DIR`` already names a directory (the
+        path is part of the cache key, so it is derived from this module's
+        own location and nothing else);
+      - merges the GPU latency-hiding flag set into ``XLA_FLAGS`` (not on a
+        TPU pin, where those flags do nothing) without clobbering flags the
+        caller or a test harness (``--xla_force_host_platform_device_count``)
+        already set.
   * ``platform_from_argv(...)`` — pre-parses ``--platform`` from the raw
     argv so launchers can pin the backend BEFORE their argparse runs
     (argparse lives after the jax import, which is too late for the env
     var).
-  * ``set_platform(...)`` — the post-import half: pins
-    ``jax_platform_name`` the way the jax gpu-performance-tips page
-    recommends.
 
-Flag availability is jaxlib-version-gated (``_GATED_GPU_FLAGS``): XLA
-deletes flags once their behavior becomes the default, and a jaxlib that
-no longer knows a flag hard-ABORTS at backend init — so every
-since-removed flag (``--xla_gpu_enable_async_collectives``,
-``--xla_gpu_enable_triton_softmax_fusion``, ...) carries the first jaxlib
-version WITHOUT it and is only emitted for provably older installs; an
-undeterminable jaxlib version fails closed (no gated flag at all).
-Everything here is a plain env-var edit — no jax import happens in this
-module at call time unless ``set_platform`` is used.
+Everything here is a plain env-var edit; this module never imports jax.
 """
 from __future__ import annotations
 
 import os
 import sys
 import warnings
+from pathlib import Path
 
 # the jax gpu-performance-tips flag set (latency-hiding scheduler + fusion
-# knobs). Safe to parse on CPU-only jaxlib builds: DebugOptions registers
-# xla_gpu_* flags regardless of backend. Only flags still present in
-# current XLA live here unconditionally; everything XLA has since deleted
-# goes through the version-gated table below.
+# knobs). Safe to parse on every jaxlib build: DebugOptions registers
+# xla_gpu_* flags regardless of backend.
 GPU_PERF_FLAGS = (
     "--xla_gpu_triton_gemm_any=True",
     "--xla_gpu_enable_latency_hiding_scheduler=true",
 )
 
-# Flags XLA has removed upstream (their behavior became the default).
-# Newer jaxlibs hard-ABORT at backend init on an unknown XLA flag, so each
-# is emitted only when the installed jaxlib is provably older than the
-# release that dropped it: (flag, first jaxlib WITHOUT the flag). When the
-# jaxlib version cannot be determined we fail CLOSED and emit none of
-# them — losing a hint flag is harmless, aborting the process is not.
-_GATED_GPU_FLAGS = (
-    # async collectives became the default in mid-0.4.x
-    ("--xla_gpu_enable_async_collectives=true", (0, 4, 30)),
-    # both dropped in the 0.5 line (still parsed by 0.4.36)
-    ("--xla_gpu_enable_triton_softmax_fusion=true", (0, 5, 0)),
-    ("--xla_gpu_enable_highest_priority_async_stream=true", (0, 5, 0)),
-)
-
-
 PLATFORMS = ("cpu", "gpu", "tpu")
+
+#: persistent compilation cache used when JAX_COMPILATION_CACHE_DIR is unset
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def platform_from_argv(argv=None) -> str | None:
     """Extract ``--platform <p>`` / ``--platform=<p>`` from raw argv
     (default: ``sys.argv``) without argparse — launchers call this at
     module import, before jax exists in the process, so the pin can land
-    in ``JAX_PLATFORM_NAME`` while it still matters. Returns None when the
+    in ``JAX_PLATFORMS`` while it still matters. Returns None when the
     flag is absent; validation happens in ``configure``."""
     argv = sys.argv[1:] if argv is None else list(argv)
     for i, tok in enumerate(argv):
@@ -82,16 +60,6 @@ def platform_from_argv(argv=None) -> str | None:
         if tok.startswith("--platform="):
             return tok.split("=", 1)[1]
     return None
-
-
-def _jaxlib_version() -> tuple | None:
-    """Installed jaxlib version triple, or None when unknown (fail closed:
-    callers must then treat every version-gated flag as unavailable)."""
-    try:
-        from importlib.metadata import version
-        return tuple(int(p) for p in version("jaxlib").split(".")[:3])
-    except Exception:                      # pragma: no cover - defensive
-        return None
 
 
 def _merge_xla_flags(new_flags) -> bool:
@@ -111,17 +79,15 @@ def configure(*, gpu_flags: bool = True,
               platform: str | None = None) -> dict:
     """Prepare the process environment for a launcher run.
 
-    Must run before the first jax import in the process — XLA parses
-    ``XLA_FLAGS`` once at backend init and never re-reads it (and jax
-    reads ``JAX_PLATFORM_NAME`` at the same moment). Idempotent: a second
-    call that would change nothing is a silent no-op, so every launcher
-    module can stage the env at import without worrying about which one
-    ran first. Returns the settings actually applied (for logging / the
-    obs run header).
+    Must run before the first jax import in the process. Idempotent: a
+    second call that would change nothing is a silent no-op, so every
+    launcher module can stage the env at import without worrying about
+    which one ran first. Returns the settings actually applied (for logging
+    / the obs run header).
 
     ``platform`` pins the jax backend ('cpu' | 'gpu' | 'tpu', typically
     from ``platform_from_argv()``). An explicit pin wins over an inherited
-    ``JAX_PLATFORM_NAME``; None leaves whatever the environment says.
+    ``JAX_PLATFORMS``; None leaves whatever the environment says.
     """
     applied: dict = {}
     changed = False
@@ -129,35 +95,31 @@ def configure(*, gpu_flags: bool = True,
         if platform not in PLATFORMS:
             raise ValueError(f"unknown platform {platform!r}; "
                              f"have {PLATFORMS}")
-        if os.environ.get("JAX_PLATFORM_NAME") != platform:
-            os.environ["JAX_PLATFORM_NAME"] = platform
+        if os.environ.get("JAX_PLATFORMS") != platform:
+            os.environ["JAX_PLATFORMS"] = platform
             changed = True
         applied["platform"] = platform
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(DEFAULT_CACHE_DIR)
+        changed = True
+    applied["compilation_cache_dir"] = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    # cache every program, not only those that took over a second: on one
+    # TPU v5e a cold exact fit compiled 393 programs, of which JAX's default
+    # threshold cached 10, so a second run still spent 28 of 35 s compiling
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+        changed = True
     if host_device_count:
         changed |= _merge_xla_flags(
             [f"--xla_force_host_platform_device_count={host_device_count}"])
         applied["host_device_count"] = host_device_count
-    if gpu_flags:
-        flags = list(GPU_PERF_FLAGS)
-        ver = _jaxlib_version()
-        if ver is not None:   # unknown version -> skip every gated flag
-            flags += [f for f, removed_in in _GATED_GPU_FLAGS
-                      if ver < removed_in]
-        changed |= _merge_xla_flags(flags)
-        applied["gpu_flags"] = flags
-    if changed and "jax" in sys.modules:   # too late for XLA_FLAGS
+    if gpu_flags and platform != "tpu":
+        changed |= _merge_xla_flags(GPU_PERF_FLAGS)
+        applied["gpu_flags"] = list(GPU_PERF_FLAGS)
+    if changed and "jax" in sys.modules:   # too late for the env vars
         warnings.warn(
-            "repro.launch.env.configure() changed XLA_FLAGS after jax was "
-            "imported; the changes will not take effect in this process",
-            RuntimeWarning, stacklevel=2)
+            "repro.launch.env.configure() changed the JAX environment after "
+            "jax was imported; the changes will not take effect in this "
+            "process", RuntimeWarning, stacklevel=2)
     applied["xla_flags"] = os.environ.get("XLA_FLAGS", "")
     return applied
-
-
-def set_platform(platform: str | None = None) -> None:
-    """Pin the jax platform ('cpu' | 'gpu' | 'tpu'). The one jax-importing
-    call here; only effective at the beginning of the program."""
-    if platform is None:
-        return
-    import jax
-    jax.config.update("jax_platform_name", platform)

@@ -428,16 +428,13 @@ def analyze(hlo: str) -> Cost:
 # compiled-program helpers (repro.analysis integration)
 #
 # The static auditor wants XLA's own cost/memory numbers NEXT TO the
-# loop-aware text analysis above, in one dict. Getting them portably is the
-# same compat minefield PR 1 patched in launch/dryrun*: the pinned JAX's
-# ``compiled.cost_analysis()`` returns a one-element LIST of dicts (newer
-# return the dict), and on CPU ``memory_analysis()`` can return None, raise,
-# or lack ``peak_memory_in_bytes`` — every attribute must be guarded.
+# loop-aware text analysis above, in one dict. A backend may have no cost
+# analysis (None), and ``memory_analysis()`` can return None or raise.
 
 
 def xla_cost(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a flat dict, or ``{}``
-    when the backend provides none (list-vs-dict and None-safe)."""
+    """``compiled.cost_analysis()`` as a dict, or ``{}`` when the backend
+    provides none."""
     try:
         from repro.distributed.compat import cost_analysis
         return cost_analysis(compiled)

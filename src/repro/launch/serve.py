@@ -18,7 +18,7 @@ from __future__ import annotations
 import argparse
 import time
 
-# XLA_FLAGS / JAX_PLATFORM_NAME must be staged BEFORE the first jax
+# XLA_FLAGS / JAX_PLATFORMS must be staged BEFORE the first jax
 # import: the latency-hiding scheduler flags are a compile-time,
 # process-level switch, and the --platform pin (which also selects the
 # Mosaic/Triton/interpret kernel lowering, kernels/backend.py — i.e.

@@ -42,16 +42,16 @@ from repro.training.step import make_train_step
 
 
 def build_mesh(spec: str):
-    """'4x2' -> mesh (data=4, model=2) over the available devices."""
+    """'4x2' -> mesh (data=4, model=2) over the first 4*2 visible devices."""
     dims = tuple(int(x) for x in spec.split("x"))
     n = math.prod(dims)
-    if n != len(jax.devices()):
+    devices = jax.devices()
+    if n > len(devices):
         raise SystemExit(
-            f"mesh {spec} needs {n} devices, have {len(jax.devices())} "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
+            f"mesh {spec} needs {n} devices, have {len(devices)}")
     names = ("data", "model")[:len(dims)] if len(dims) <= 2 else \
         ("pod", "data", "model")
-    return make_mesh(dims, names)
+    return make_mesh(dims, names, devices=devices[:n])
 
 
 def synthetic_batches(vocab: int, batch: int, seq: int, steps: int,

@@ -203,14 +203,18 @@ def freeze(result, *, precision: str = "f32") -> FrozenArtifact:
     drift the precision tests pin (tile rounding only — every consumer
     still accumulates f32).
     """
-    if result.fmap is not None:
-        return freeze_map(result.fmap, result.state.centroids,
-                          result.state.cardinalities, precision=precision)
+    # a mesh fit leaves its state on every device of the mesh; the
+    # artifact is served from one (a Mosaic kernel cannot be partitioned
+    # by XLA), so it is built from a host copy
+    state, fmap = jax.tree.map(jnp.asarray, jax.device_get(
+        (result.state, result.fmap)))
+    if fmap is not None:
+        return freeze_map(fmap, state.centroids, state.cardinalities,
+                          precision=precision)
     if result.spec is None:
         raise ValueError(
             "cannot freeze an exact-path FitResult without its KernelSpec "
             "(FitResult.spec) — prediction would use the wrong kernel")
-    state = result.state
     c, d = state.medoids.shape
     arrays = dict(medoids=jnp.asarray(state.medoids, jnp.float32),
                   medoid_diag=jnp.asarray(state.medoid_diag, jnp.float32))

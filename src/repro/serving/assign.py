@@ -281,6 +281,7 @@ class AssignService:
         self._pending_rows = 0
         self._uid = 0
         self._programs: dict[int, object] = {}
+        self._compiled: dict[int, object] = {}
         self._fmap = (artifact.feature_map()
                       if artifact.kind in SKETCH_KINDS else None)
         if cfg.warm:
@@ -327,10 +328,17 @@ class AssignService:
     def _program(self, bucket: int):
         if bucket not in self._programs:
             jitfn, x0, args, kw, post = self._entry(bucket)
-            compiled = jitfn.lower(x0, *args, **kw).compile()
+            compiled = self._compiled[bucket] = \
+                jitfn.lower(x0, *args, **kw).compile()
             self._programs[bucket] = \
                 lambda xp, c=compiled, a=args, p=post: p(c(xp, *a))
         return self._programs[bucket]
+
+    def program_text(self, bucket: int) -> str:
+        """Optimized HLO of one bucket's compiled program — shows which
+        kernels it runs (a Mosaic kernel is a ``tpu_custom_call``)."""
+        self._program(bucket)
+        return self._compiled[bucket].as_text()
 
     # -- queue --------------------------------------------------------------
 

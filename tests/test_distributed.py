@@ -256,6 +256,12 @@ def test_staging_tail_batch_smaller_than_mesh():
             staged = km2.fit(src)
         ny_same = bool((np.asarray(inline.state.centroids)
                         == np.asarray(staged.state.centroids)).all())
+        # built from the staged (sharded) batch, the map and the served
+        # artifact still live on one device, where a Mosaic kernel runs
+        from repro.serving import freeze
+        ny_one_device = all(len(a.devices()) == 1 for a in
+                            [staged.fmap.landmarks, staged.fmap.proj]
+                            + list(freeze(staged).arrays.values()))
 
         # non-divisible FIRST batch: k-means++ must seed over the unpadded
         # rows, or ghost rows shift every D^2 draw and the distributed fit
@@ -282,11 +288,13 @@ def test_staging_tail_batch_smaller_than_mesh():
         print(json.dumps({
             "csr_total": float(np.asarray(res_csr.state.cardinalities).sum()),
             "dense_total": dense_total, "n": n, "ny_same": ny_same,
+            "ny_one_device": ny_one_device,
             "seed_same": seed_same, "exact_batches": exact_batches}))
     """)
     assert res["csr_total"] == res["n"]      # ghost rows masked out
     assert res["dense_total"] == res["n"]
     assert res["ny_same"], "staged Nystrom sampling diverged from inline"
+    assert res["ny_one_device"], "map or artifact spread over the mesh"
     assert res["seed_same"], "non-divisible first batch: seeding diverged"
     assert res["exact_batches"] == 2         # tail batch staged, not crashed
 

@@ -151,7 +151,7 @@ class _FakeMem:
     temp_size_in_bytes = 100
     argument_size_in_bytes = 40
     output_size_in_bytes = 8
-    # no peak_memory_in_bytes attr: the CPU/old-JAX shape
+    peak_memory_in_bytes = 120
 
 
 class _FakeCompiled:
@@ -169,26 +169,30 @@ class _FakeCompiled:
 
 
 def test_xla_cost_list_vs_dict_vs_none():
-    # pinned JAX: one-element list of per-computation dicts
-    assert hlocost.xla_cost(_FakeCompiled([{"flops": 5.0}])) == {
-        "flops": 5.0}
-    # newer JAX: the dict directly
+    # the installed JAX returns the dict directly
     assert hlocost.xla_cost(_FakeCompiled({"flops": 7.0})) == {"flops": 7.0}
-    # backends with no analysis: None and [] both collapse to {}
+    # backends with no analysis: None and {} both collapse to {}
     assert hlocost.xla_cost(_FakeCompiled(None)) == {}
-    assert hlocost.xla_cost(_FakeCompiled([])) == {}
+    assert hlocost.xla_cost(_FakeCompiled({})) == {}
+    # a real compiled program yields a plain dict with XLA's flop count
+    compiled = jax.jit(lambda a: a @ a).lower(
+        jnp.ones((8, 8), jnp.float32)).compile()
+    cost = hlocost.xla_cost(compiled)
+    assert type(cost) is dict and cost.get("flops", 0) > 0
 
 
 def test_xla_memory_guarded_on_cpu_shapes():
     empty = {"bytes_per_device": None, "argument_bytes": None,
              "output_bytes": None, "peak_bytes": None}
-    # memory_analysis() raising (CPU) or returning None: all-None dict
+    # memory_analysis() raising or returning None: all-None dict
     assert hlocost.xla_memory(_FakeCompiled({}, mem="raise")) == empty
     assert hlocost.xla_memory(_FakeCompiled({}, mem=None)) == empty
-    # missing peak_memory_in_bytes attr: conservative temp+args+out bound
+    # the installed shape reports the allocator's own peak
     got = hlocost.xla_memory(_FakeCompiled({}, mem=_FakeMem()))
-    assert got["peak_bytes"] == 148
+    assert got["peak_bytes"] == 120
+    assert got["bytes_per_device"] == 100
     assert got["argument_bytes"] == 40
+    assert got["output_bytes"] == 8
 
 
 def test_compiled_cost_terms_matmul():

@@ -308,13 +308,21 @@ def test_distributed_rls_selection_matches_single_host():
         labels = np.asarray(dist.predict(jnp.asarray(X)))
         label_same = bool(
             (labels == np.asarray(host.predict(jnp.asarray(X)))).all())
+        # the map and the served artifact live on one device: a Mosaic
+        # kernel outside shard_map cannot be partitioned over the mesh
+        from repro.serving import freeze
+        one_device = [len(a.devices()) == 1 for a in
+                      [dist.fmap.landmarks, dist.fmap.proj]
+                      + list(freeze(dist).arrays.values())]
         print(json.dumps({
             "lm_same": lm_same, "label_same": label_same,
+            "one_device": all(one_device),
             "nmi": nmi(y, labels),
             "total": float(np.asarray(dist.state.cardinalities).sum()),
             "n": len(X)}))
     """)
     assert res["lm_same"], "mesh RLS selected different landmarks"
     assert res["label_same"]
+    assert res["one_device"], "map or artifact spread over the mesh"
     assert res["nmi"] >= 0.9
     assert res["total"] == res["n"]      # ghost rows masked out

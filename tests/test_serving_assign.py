@@ -248,6 +248,20 @@ def test_service_warm_compiles_ladder_and_only_ladder():
     assert svc.compiled_programs == len(DEFAULT_BUCKETS)
 
 
+def test_service_program_text_is_the_bucket_program():
+    """``program_text`` reads the HLO of the program a bucket runs (the
+    chip smoke searches it for the Mosaic kernel), compiling nothing new."""
+    art, x, _ = _blob_artifact("nystrom")
+    svc = AssignService(art)
+    texts = {b: svc.program_text(b) for b in DEFAULT_BUCKETS}
+    assert svc.compiled_programs == len(DEFAULT_BUCKETS)
+    for b, text in texts.items():
+        assert "HloModule" in text
+        assert f"f32[{b},{art.in_dim}]" in text
+    svc.predict(x[:3])
+    assert svc.compiled_programs == len(DEFAULT_BUCKETS)
+
+
 def test_service_packs_fifo_and_completes_all():
     """Many small requests ride one bucket; a large one drains chunked —
     every request gets exactly its own rows' labels back."""
