@@ -173,6 +173,15 @@ def collectives_per_iteration(n_clusters: int, m: int) -> dict:
             "final_psum": 1, "final_psum_bytes": payload}
 
 
+def _nystrom_from_rows(x: jax.Array, idx: jax.Array, spec: KernelSpec):
+    """Nystrom map from the rows ``idx`` of a mesh-resident batch. Only
+    those m rows come through the host: the map is built on one device,
+    since a Mosaic kernel cannot be partitioned by XLA."""
+    from repro.approx import nystrom_from_landmarks
+    landmarks = jax.device_get(jnp.take(x, idx, axis=0))
+    return nystrom_from_landmarks(jnp.asarray(landmarks), spec)
+
+
 class DistributedEmbedKMeans:
     """Mesh-resident embedded-space mini-batch k-means.
 
@@ -217,37 +226,40 @@ class DistributedEmbedKMeans:
     def _ensure_fmap(self, sample):
         """Sample the feature map from a batch (dense rows or CSRBatch); a
         pre-staged first batch passes a structural sample instead — enough
-        for the data-oblivious maps (sketch/tensorsketch read only d; a
-        dense StagedBatch hands its mesh-resident rows to RFF/Nystrom).
+        for the data-oblivious maps (sketch/tensorsketch/RFF read only d).
 
-        Nystrom + ``selector="rls"`` on a staged dense batch takes the
-        mesh-native route (``_make_nystrom_rls``): leverage scores from
-        per-device partial sketches, one psum, the staged batch reused —
-        no second pass over the stream and no host-side gather of rows.
+        Nystrom on a staged dense batch selects its landmarks on the mesh,
+        over the UNPADDED rows, so it sees exactly what the inline path's
+        raw batch gives it (ghost rows must not alter the model): for
+        ``selector="rls"`` from per-device partial leverage sketches and
+        one psum (``_make_nystrom_rls``). Only the m landmark rows leave
+        the mesh — no second pass over the stream, no host-side gather of
+        the batch.
         """
         if self.fmap is None:
             from repro import approx
-            from repro.approx.selectors import name_of
+            from repro.approx import selectors
             cfg = self.cfg
-            if (cfg.method == "nystrom" and name_of(cfg.selector) == "rls"
-                    and isinstance(sample, StagedBatch)
-                    and not sample.sparse):
-                m = cfg.embed_dim or approx.default_embed_dim(cfg.n_clusters)
-                self.fmap = self._make_nystrom_rls(sample, m)
-                return self.fmap
+            m = cfg.embed_dim or approx.default_embed_dim(cfg.n_clusters)
             if isinstance(sample, StagedBatch):
-                # dense: the UNPADDED rows, so a data-dependent map
-                # (Nystrom landmarks) sees exactly what the inline path's
-                # raw batch gives it — ghost rows must not alter the model.
-                # A host copy, as that batch is: the map is built on one
-                # device (a Mosaic kernel cannot be partitioned by XLA).
+                if cfg.method == "nystrom" and not sample.sparse:
+                    if selectors.name_of(cfg.selector) == "rls":
+                        self.fmap = self._make_nystrom_rls(sample, m)
+                    else:
+                        idx = selectors.resolve(cfg.selector).select_indices(
+                            jax.random.PRNGKey(cfg.seed),
+                            sample.x[:sample.n], m, cfg.kernel)
+                        self.fmap = _nystrom_from_rows(sample.x, idx,
+                                                       cfg.kernel)
+                    return self.fmap
+                # the data-oblivious maps read only the column count
                 sample = (CSRBatch(data=np.zeros((0,), np.float32),
                                    indices=np.zeros((0,), np.int32),
                                    indptr=np.zeros((1,), np.int32),
                                    shape=(0, sample.d))
                           if sample.sparse
-                          else np.asarray(sample.x[:sample.n]))
-            m = cfg.embed_dim or approx.default_embed_dim(cfg.n_clusters)
+                          else jax.ShapeDtypeStruct((sample.n, sample.d),
+                                                    jnp.float32))
             self.fmap = approx.make_feature_map(
                 cfg.method, jax.random.PRNGKey(cfg.seed), sample, m,
                 cfg.kernel, orthogonal=cfg.rff_orthogonal,
@@ -264,7 +276,7 @@ class DistributedEmbedKMeans:
         shard-locally — no device ever sees another shard's rows, and the
         already-staged batch is reused for the embedding right after.
         """
-        from repro.approx import nystrom_from_landmarks, selectors
+        from repro.approx import selectors
 
         cfg = self.cfg
         spec = cfg.kernel
@@ -294,10 +306,7 @@ class DistributedEmbedKMeans:
             out_specs=P(self.row_axes), check_vma=False))(
                 st.x, st.wgt, pilot, whiten)
         idx = sel.gumbel_top_m(key, scores[:st.n], gids, m)
-        # the m landmark rows, through the host: the map is built on one
-        # device (a Mosaic kernel cannot be partitioned by XLA)
-        landmarks = jax.device_get(jnp.take(st.x, idx, axis=0))
-        return nystrom_from_landmarks(jnp.asarray(landmarks), spec)
+        return _nystrom_from_rows(st.x, idx, spec)
 
     # -- staging: host batch -> mesh-resident, pre-sharded -----------------
 

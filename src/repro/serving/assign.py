@@ -280,8 +280,8 @@ class AssignService:
         self._queue: collections.deque[_Request] = collections.deque()
         self._pending_rows = 0
         self._uid = 0
-        self._programs: dict[int, object] = {}
-        self._compiled: dict[int, object] = {}
+        # bucket -> (compiled program, trailing dynamic args, postprocessor)
+        self._programs: dict[int, tuple] = {}
         self._fmap = (artifact.feature_map()
                       if artifact.kind in SKETCH_KINDS else None)
         if cfg.warm:
@@ -325,20 +325,17 @@ class AssignService:
                 (a[w_key], a[aux_key], a["v"], a["csq"]), kw,
                 lambda out: out[0])
 
-    def _program(self, bucket: int):
+    def _program(self, bucket: int) -> tuple:
         if bucket not in self._programs:
             jitfn, x0, args, kw, post = self._entry(bucket)
-            compiled = self._compiled[bucket] = \
-                jitfn.lower(x0, *args, **kw).compile()
-            self._programs[bucket] = \
-                lambda xp, c=compiled, a=args, p=post: p(c(xp, *a))
+            self._programs[bucket] = (
+                jitfn.lower(x0, *args, **kw).compile(), args, post)
         return self._programs[bucket]
 
     def program_text(self, bucket: int) -> str:
         """Optimized HLO of one bucket's compiled program — shows which
         kernels it runs (a Mosaic kernel is a ``tpu_custom_call``)."""
-        self._program(bucket)
-        return self._compiled[bucket].as_text()
+        return self._program(bucket)[0].as_text()
 
     # -- queue --------------------------------------------------------------
 
@@ -392,7 +389,8 @@ class AssignService:
             xp[ofs:ofs + t] = req.x[s:s + t]
             ofs += t
         t0 = time.perf_counter()
-        labels = self._program(bucket)(jnp.asarray(xp))
+        compiled, args, post = self._program(bucket)
+        labels = post(compiled(jnp.asarray(xp), *args))
         labels = np.asarray(jax.block_until_ready(labels))[:total]
         compute_s = time.perf_counter() - t0
         ofs = 0
