@@ -124,9 +124,12 @@ line; the lines that close the loop with the planner:
 count/total/max/mean digest that ``benchmarks/common.record_bench`` stores
 in ``results/BENCH_*.json``. For device-side timelines, wrap a run with
 ``repro.obs.start_profile(logdir)``/``stop_profile()`` and open the dump
-in TensorBoard — the hot paths are labelled with ``obs:*`` named scopes
-(``obs:gram_panel_build``, ``obs:engine_stats[mode]``, ``obs:psum_*``,
-``obs:embed_phi``, ``obs:stage``).
+in TensorBoard — device work is labelled with ``obs:*`` named scopes
+(``obs:gram_panel_build``, ``obs:engine_stats[mode]``, ``obs:psum_*``),
+host steps with ``repro.obs.span`` (``outer/*`` in the mesh outer loop,
+``obs:stage``, ``obs:embed_phi``). A recorded span is also a
+``{"kind": "span", ...}`` line: duration, parent span, and the lowerings
+that ran inside it.
 
 These are RUNTIME metrics; clustering QUALITY metrics (accuracy, NMI,
 elbow, displacement) live in ``repro.core.metrics``.

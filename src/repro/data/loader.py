@@ -117,14 +117,14 @@ class PrefetchLoader:
         return False
 
     def _produce(self) -> None:
-        from repro.obs import trace as obs_trace
+        from repro.obs.trace import span
         rec = self._rec
         try:
             for k, batch in enumerate(self._src):
                 if self._stop.is_set():
                     return
                 t0 = time.perf_counter()
-                with obs_trace.annotate("obs:stage"):
+                with span("obs:stage"):
                     staged = self._stage(batch)
                 if rec.enabled:
                     rec.series("prefetch/stage_seconds",

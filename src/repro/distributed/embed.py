@@ -52,7 +52,7 @@ from repro.data.sparse import (CSRBatch, concat_csr, is_sparse, slice_rows,
                                take_rows)
 from repro.obs import memory as obs_memory
 from repro.obs import resolve as resolve_recorder
-from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 
 from .compat import shard_map
 from .mesh import auto_axes, axis_size, ghost_row_ids, row_axes_of
@@ -316,10 +316,10 @@ class DistributedEmbedKMeans:
         in ``fit``); the H2D copies land pre-sharded on the mesh."""
         if isinstance(xb, StagedBatch):
             return xb
-        # timer + host-timeline annotation: staging usually runs on the
-        # prefetch producer thread, so the trace shows whether H2D staging
-        # overlaps the consumer's compute (the whole point of §3.3).
-        with self.rec.timer("stage/seconds"), obs_trace.annotate("obs:stage"):
+        # host span (and a ``span`` record when recording): staging usually
+        # runs on the prefetch producer thread, so the trace shows whether
+        # H2D staging overlaps the consumer's compute (the point of §3.3).
+        with span("obs:stage", self.rec):
             if is_sparse(xb):
                 return self._stage_csr(xb)
             return self._stage_dense(np.asarray(xb, np.float32))
@@ -433,7 +433,7 @@ class DistributedEmbedKMeans:
         per device, never [n, d]."""
         from repro.kernels.precision import resolve_precision
         prec = resolve_precision(self.cfg.precision)
-        with obs_trace.annotate("obs:embed_phi"):
+        with span("obs:embed_phi"):
             if st.sparse:
                 fn = self._embed_fn(("csr", st.rows, st.d))
                 z = fn(self.fmap, st.data, st.indices, st.indptr)

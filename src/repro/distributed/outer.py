@@ -41,6 +41,7 @@ from repro.core.landmarks import (choose_landmarks, num_landmarks,
 from repro.core.minibatch import BatchStats, FitResult, GlobalState, MiniBatchConfig
 from repro.obs import memory as obs_memory
 from repro.obs import resolve as resolve_recorder
+from repro.obs.trace import span
 
 from .compat import shard_map
 from .inner import (DistributedInnerConfig, collectives_per_iteration,
@@ -106,6 +107,7 @@ class DistributedMiniBatchKMeans:
             s_step=getattr(cfg, "s_step", 1))
         self._row_sharding = NamedSharding(mesh, P(row_axes, None))
         self._bill_cache: dict = {}
+        self._fits = 0                 # serial of the next fit (span tag)
 
     # -- helpers -----------------------------------------------------------
 
@@ -254,8 +256,17 @@ class DistributedMiniBatchKMeans:
     def fit(self, batches: Iterable[np.ndarray], *,
             state: Optional[GlobalState] = None,
             checkpoint_cb=None) -> FitResult:
+        """Run the outer loop over ``batches``. Host spans name its steps
+        on the profiler's clock (``repro.obs.trace.span``; records too
+        when the recorder is enabled): ``outer/fit`` (tag ``fit``, this
+        instance's fit serial), ``outer/batch`` (``fit``, ``batch``,
+        ``rows``) and, covering each batch in program order,
+        ``outer/stage``, ``outer/landmarks``, ``outer/diag``,
+        ``outer/seed`` (first batch only), ``outer/init``, ``outer/inner``,
+        ``outer/merge``, ``outer/sync`` (the blocking reads),
+        ``outer/checkpoint`` (with ``checkpoint_cb``) and ``outer/record``
+        (recorder only)."""
         cfg = self.cfg
-        spec = cfg.kernel
         rec = self.rec
         monitor = None
         if rec.enabled:
@@ -264,20 +275,31 @@ class DistributedMiniBatchKMeans:
         key = jax.random.PRNGKey(cfg.seed)
         history: list[BatchStats] = []
         start = int(state.batches_done) if state is not None else 0
+        fit_no = self._fits
+        self._fits += 1
 
-        for i, xb in enumerate(batches, start=start):
-            t_batch = time.perf_counter()
-            xb = np.asarray(xb, np.float32)
+        with span("outer/fit", rec, fit=fit_no):
+            for i, xb in enumerate(batches, start=start):
+                tags = dict(fit=fit_no, batch=i)
+                with span("outer/batch", rec, rows=len(xb), **tags):
+                    state = self._batch(i, xb, key, state, history,
+                                        checkpoint_cb, monitor, tags)
+                rec.batch_boundary(i)
+            if state is None:
+                raise ValueError("empty batch iterable")
+        return FitResult(state, history, spec=cfg.kernel)
+
+    def _batch(self, i: int, xb, key, state, history, checkpoint_cb,
+               monitor, tags: dict) -> GlobalState:
+        """One batch of ``fit``: each step under its own span."""
+        cfg = self.cfg
+        spec = cfg.kernel
+        rec = self.rec
+        t_batch = time.perf_counter()
+        with span("outer/stage", rec, **tags):
+            xb = unpadded = np.asarray(xb, np.float32)
             n = len(xb)
             idx = ghost_row_ids(n, self.d_size)
-            # pure per-batch schedule — batch i's draws depend only on
-            # (cfg.seed, i), so a checkpoint-resumed fit replays the same
-            # landmarks as the uninterrupted run (same fix as
-            # core/minibatch.fit and the embedded path).
-            k_lm, k_pp = jax.random.split(jax.random.fold_in(key, i))
-            # landmark selection over the UNPADDED rows (ghost-bias fix;
-            # see _choose_landmarks) BEFORE the batch is padded.
-            l_idx, n_l = self._choose_landmarks(k_lm, xb, len(idx))
             if len(idx):
                 # Replicate head rows so shapes divide the mesh; ``wgt``
                 # masks them out of the cost and both medoid argmins, and
@@ -289,14 +311,25 @@ class DistributedMiniBatchKMeans:
             x = self._put_rows(xb)
             wgt = jax.device_put(wgt_host,
                                  NamedSharding(self.mesh, P(self.row_axes)))
+        with span("outer/landmarks", rec, **tags):
+            # pure per-batch schedule — batch i's draws depend only on
+            # (cfg.seed, i), so a checkpoint-resumed fit replays the same
+            # landmarks as the uninterrupted run (same fix as
+            # core/minibatch.fit and the embedded path).
+            k_lm, k_pp = jax.random.split(jax.random.fold_in(key, i))
+            # landmark selection over the UNPADDED rows (ghost-bias fix;
+            # see _choose_landmarks).
+            l_idx, n_l = self._choose_landmarks(k_lm, unpadded, len(idx))
+        with span("outer/diag", rec, **tags):
             diag = shard_map(
                 lambda xl: spec.diag(xl), mesh=self.mesh,
                 in_specs=P(self.row_axes, None), out_specs=P(self.row_axes),
                 check_vma=False)(x)
             landmarks = jnp.take(x, l_idx, axis=0)   # [L, d] replicated
 
-            first = state is None
-            if first:
+        first = state is None
+        if first:
+            with span("outer/seed", rec, **tags):
                 # distributed adaptation: k-means++ seeds FROM THE LANDMARK
                 # SET (the subspace centroids live in anyway, §3.2) — keeps
                 # the D^2 sampling single-pass and mesh-local.
@@ -304,6 +337,8 @@ class DistributedMiniBatchKMeans:
                     landmarks, spec.diag(landmarks), k_pp,
                     n_clusters=cfg.n_clusters, spec=spec)
                 seed_x = jnp.take(landmarks, seeds, axis=0)
+        with span("outer/init", rec, **tags):
+            if first:
                 u0, k_tilde = self._init_labels(x, diag, seed_x,
                                                 spec.diag(seed_x))
                 state_in = GlobalState(seed_x, spec.diag(seed_x),
@@ -314,17 +349,23 @@ class DistributedMiniBatchKMeans:
                                                 state.medoid_diag)
                 state_in = state
 
+        with span("outer/inner", rec, **tags):
             res = distributed_kkmeans_fit(
                 self.mesh, x, landmarks, l_idx, diag, u0, cfg=self.inner_cfg,
                 wgt=wgt)
+        with span("outer/merge", rec, **tags):
             state, disp = self._medoid_merge(x, diag, res, k_tilde, state_in,
                                              first, wgt)
+        with span("outer/sync", rec, **tags):
             history.append(BatchStats(
                 inner_iters=int(res.n_iter), cost=float(res.cost),
-                displacement=np.asarray(disp), counts=np.asarray(res.counts)))
-            if checkpoint_cb is not None:
+                displacement=np.asarray(disp),
+                counts=np.asarray(res.counts)))
+        if checkpoint_cb is not None:
+            with span("outer/checkpoint", rec, **tags):
                 checkpoint_cb(state, i)
-            if rec.enabled:
+        if rec.enabled:
+            with span("outer/record", rec, **tags):
                 dt = time.perf_counter() - t_batch
                 n_iter = history[-1].inner_iters
                 # statically-audited bill: per-sync count x n_iter loop
@@ -357,7 +398,4 @@ class DistributedMiniBatchKMeans:
                 # the timing unit is this process (a multi-host launch
                 # contributes one per host).
                 monitor.observe(i, {jax.process_index(): dt}, n_rows=len(xb))
-                rec.batch_boundary(i)
-        if state is None:
-            raise ValueError("empty batch iterable")
-        return FitResult(state, history, spec=cfg.kernel)
+        return state

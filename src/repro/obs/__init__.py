@@ -1,5 +1,5 @@
-"""repro.obs — the flight recorder: jit-safe runtime metrics, profiler
-spans, and HBM watermarks for the clustering runtime.
+"""repro.obs — the flight recorder: jit-safe runtime metrics, host spans
+on the profiler's clock, and HBM watermarks for the clustering runtime.
 
 Three rules make it safe to leave on in production:
 
@@ -17,17 +17,21 @@ Three rules make it safe to leave on in production:
 
 Contrast with ``repro.core.metrics``: that module scores clustering
 *quality* (NMI, accuracy, elbow); this package records where the *runtime*
-spends time and bytes. ``repro.obs.export.summarize`` folds a JSONL event
-log into the ``results/BENCH_*.json`` perf trajectory
-(``benchmarks.common.record_bench``) — the measured-cost substrate the
-self-tuning planner consumes.
+spends time and bytes. ``repro.obs.trace.span`` is the one host span: a
+profiler annotation always, plus a ``span`` record (duration, parent,
+lowerings inside it) when the recorder is enabled. The mesh outer loop
+(``repro.distributed.outer``) opens ``outer/fit``, ``outer/batch`` and one
+child per step of a batch (``outer/stage`` ... ``outer/record``).
+``repro.obs.export.summarize`` folds a JSONL event log into per-name
+aggregates, which ``benchmarks.common.record_bench`` embeds in
+``results/BENCH_*.json``.
 """
 from .recorder import (JsonlRecorder, MetricsRecorder, NullRecorder, NULL,
                        resolve)
-from .trace import annotate, span, start_profile, stop_profile
+from .trace import span, start_profile, stop_profile
 from . import export, memory
 
 __all__ = [
     "JsonlRecorder", "MetricsRecorder", "NullRecorder", "NULL", "resolve",
-    "annotate", "span", "start_profile", "stop_profile", "export", "memory",
+    "span", "start_profile", "stop_profile", "export", "memory",
 ]

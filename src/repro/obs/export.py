@@ -7,8 +7,8 @@ device inventory, jax version) plus whatever the caller knows (the
 self-describing: no out-of-band context needed to interpret it.
 
 ``summarize`` reduces a log to per-name aggregates (count/total/mean/max
-for timers, series and gauges; final totals for counters; the last
-measured-vs-predicted watermark pair) — the compact form
+of span seconds and of series and gauge values; final totals for
+counters; the last measured-vs-predicted watermark pair) — the compact form
 ``benchmarks.common.record_bench`` embeds into ``BENCH_<name>.json`` so
 the perf trajectory carries measured costs, not just end-to-end wall time.
 """
@@ -75,8 +75,8 @@ def summarize(path: str) -> dict:
         kind = rec.get("kind")
         if kind == "counter":
             counters[rec["name"]] = rec.get("total", 0.0)
-        elif kind in ("timer", "series", "gauge"):
-            v = rec.get("seconds") if kind == "timer" else rec.get("value")
+        elif kind in ("span", "series", "gauge"):
+            v = rec.get("seconds") if kind == "span" else rec.get("value")
             if v is None:
                 continue
             s = stats.setdefault(rec["name"], {"count": 0, "total": 0.0,

@@ -10,17 +10,18 @@
                           live ``jax.Array`` — it is parked unconverted and
                           drained in one batched fetch at ``batch_boundary``
                           (never a mid-loop blocking sync)
-  timer(name)             context manager measuring host wall seconds
   event(name, **fields)   structured one-off (straggler_detected, resume,
                           hbm_watermark)
+  record_span(name, **f)  one closed host span, written by
+                          ``repro.obs.trace.span`` (the only caller)
   batch_boundary(batch)   drain deferred device scalars + flush the sink
 
 ``NullRecorder`` (singleton ``NULL``) is the zero-overhead default: every
-hook is a no-op, ``timer`` returns a shared null context manager, and no
-state is kept. ``JsonlRecorder`` appends one JSON object per record to a
-file; it is thread-safe (the PrefetchLoader producer thread records stage
-timings concurrently with the consumer loop) and buffers lines host-side,
-flushing only at batch boundaries and on ``close``.
+hook is a no-op and no state is kept. ``JsonlRecorder`` appends one JSON
+object per record to a file; it is thread-safe (the embedded fit's staging
+records its spans on the PrefetchLoader producer thread, concurrently with
+the consumer loop) and buffers lines host-side, flushing only at batch
+boundaries and on ``close``.
 
 Nothing in this module imports jax at call time beyond ``device_get`` in
 the drain — recorder hooks must stay cheap enough to leave on.
@@ -42,20 +43,6 @@ import time
 from typing import Optional
 
 
-class _NullTimer:
-    """Shared no-op context manager (``NullRecorder.timer``)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_TIMER = _NullTimer()
-
-
 class MetricsRecorder:
     """The contract (and the no-op base — see module docstring)."""
 
@@ -73,8 +60,8 @@ class MetricsRecorder:
     def event(self, name: str, **fields) -> None:
         pass
 
-    def timer(self, name: str, **tags):
-        return _NULL_TIMER
+    def record_span(self, name: str, **fields) -> None:
+        pass
 
     def batch_boundary(self, batch: int) -> None:
         pass
@@ -100,25 +87,6 @@ NULL = NullRecorder()
 def resolve(recorder: Optional[MetricsRecorder]) -> MetricsRecorder:
     """The threading currency: ``recorder=None`` anywhere means ``NULL``."""
     return NULL if recorder is None else recorder
-
-
-class _Timer:
-    __slots__ = ("_rec", "_name", "_tags", "_t0", "seconds")
-
-    def __init__(self, rec: "JsonlRecorder", name: str, tags: dict):
-        self._rec = rec
-        self._name = name
-        self._tags = tags
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self._t0
-        self._rec._append(dict(kind="timer", name=self._name,
-                               seconds=self.seconds, **self._tags))
-        return False
 
 
 class JsonlRecorder(MetricsRecorder):
@@ -175,8 +143,8 @@ class JsonlRecorder(MetricsRecorder):
     def event(self, name: str, **fields) -> None:
         self._append(dict(kind="event", name=name, **fields))
 
-    def timer(self, name: str, **tags):
-        return _Timer(self, name, tags)
+    def record_span(self, name: str, **fields) -> None:
+        self._append(dict(kind="span", name=name, **fields))
 
     def batch_boundary(self, batch: int) -> None:
         """Drain deferred device scalars (one batched fetch) and flush."""

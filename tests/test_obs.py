@@ -15,7 +15,7 @@ from repro.core.minibatch import (_first_batch_step, _next_batch_step,
                                   fit, fit_dataset)
 from repro.data.synthetic import make_blobs
 from repro.obs import (NULL, JsonlRecorder, MetricsRecorder, NullRecorder,
-                       export, resolve)
+                       export, resolve, span)
 
 
 def _events(path, kind=None, name=None):
@@ -38,7 +38,7 @@ def test_null_recorder_contract():
     r.gauge("g", 1.0)
     r.series("s", jnp.float32(1.0))
     r.event("e", detail="x")
-    with r.timer("t"):
+    with span("t", r, batch=0):
         pass
     r.batch_boundary(0)
     r.close()
@@ -56,8 +56,9 @@ def test_jsonl_recorder_roundtrip(tmp_path):
     rec.gauge("queue", 2, batch=0)
     rec.series("wall", 0.25, batch=0)
     rec.series("cost", jnp.float32(3.5), batch=0)   # deferred device value
-    with rec.timer("stage") as t:
-        pass
+    with span("stage", rec, batch=0) as t:
+        with span("stage/inner", rec) as u:
+            pass
     rec.event("hbm_watermark", batch=0, source="host_rss",
               measured_bytes=100, peak_bytes=100, predicted_bytes=80.0)
     rec.batch_boundary(0)
@@ -73,7 +74,12 @@ def test_jsonl_recorder_roundtrip(tmp_path):
     # the deferred jax scalar was drained to a plain float at the boundary
     cost = _events(path, kind="series", name="cost")
     assert len(cost) == 1 and cost[0]["value"] == pytest.approx(3.5)
-    assert t.seconds >= 0.0
+    assert t.seconds >= u.seconds >= 0.0
+    outer, = _events(path, kind="span", name="stage")
+    inner, = _events(path, kind="span", name="stage/inner")
+    assert outer["parent"] is None and inner["parent"] == outer["id"]
+    assert outer["batch"] == 0 and outer["seconds"] == t.seconds
+    assert outer["lowerings"] == inner["lowerings"] == 0
     # every line is valid JSON (numpy/jax leak would have raised in dumps)
     with open(path) as f:
         for line in f:
@@ -83,6 +89,7 @@ def test_jsonl_recorder_roundtrip(tmp_path):
     assert s["events"] == len(export.read_events(path))
     assert s["counters"]["collectives/psum"] == 12
     assert s["stats"]["wall"]["count"] == 1
+    assert s["stats"]["stage"]["total"] == pytest.approx(t.seconds)
     assert s["last_watermark"]["predicted_bytes"] == 80.0
 
 
@@ -204,6 +211,85 @@ def test_distributed_exact_recorder_identity(tmp_path):
     timings = _events(path, kind="event", name="batch_timing")
     assert len(timings) == 2
     assert str(jax.process_index()) in timings[0]["timings"]
+
+    # every host span record carries the lowerings inside it; a batch's
+    # steps cover it, so they account for every lowering of the batch
+    spans = _events(path, kind="span")
+    assert spans and all(isinstance(r["lowerings"], int)
+                         and r["lower_s"] >= 0 for r in spans)
+    for b in _events(path, kind="span", name="outer/batch"):
+        kids = [r for r in spans if r["parent"] == b["id"]]
+        assert b["lowerings"] == sum(r["lowerings"] for r in kids)
+
+
+OUTER_STEPS = ["outer/stage", "outer/landmarks", "outer/diag", "outer/seed",
+               "outer/init", "outer/inner", "outer/merge", "outer/sync",
+               "outer/checkpoint", "outer/record"]
+
+
+def _host_spans(logdir, prefix):
+    """(name, start_ns, end_ns, stats) of the host-plane events of a
+    profile whose name starts with ``prefix``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefix)]
+
+
+def test_outer_loop_spans_on_the_profiler_clock(tmp_path):
+    """A two-batch mesh fit under the profiler: one ``outer/fit``, an
+    ``outer/batch`` per batch with its tag, the steps nested inside in
+    program order and covering it (``outer/seed`` in the first batch
+    only), and JSONL span records with the same names, parents and
+    count."""
+    from repro.distributed.mesh import make_test_mesh
+    from repro.distributed.outer import DistributedMiniBatchKMeans
+
+    x, _ = make_blobs(128, 6, 3, sep=6.0, seed=5)
+    mesh = make_test_mesh({"data": 1})
+    path = str(tmp_path / "spans.jsonl")
+    logdir = str(tmp_path / "profile")
+    with JsonlRecorder(path) as rec:
+        km = DistributedMiniBatchKMeans(mesh, _exact_cfg(c=3, b=2),
+                                        recorder=rec)
+        with jax.profiler.trace(logdir):
+            km.fit([x[:64], x[64:]], checkpoint_cb=lambda st, i: None)
+
+    prof = sorted(_host_spans(logdir, "outer/"), key=lambda e: e[1])
+    fits = [e for e in prof if e[0] == "outer/fit"]
+    batches = [e for e in prof if e[0] == "outer/batch"]
+    assert len(fits) == 1 and fits[0][3]["fit"] == 0
+    assert [b[3]["batch"] for b in batches] == [0, 1]
+    for b in batches:
+        assert fits[0][1] <= b[1] and b[2] <= fits[0][2]
+        kids = [e for e in prof if e[0] in OUTER_STEPS
+                and e[3]["batch"] == b[3]["batch"]]
+        want = OUTER_STEPS if b[3]["batch"] == 0 else \
+            [n for n in OUTER_STEPS if n != "outer/seed"]
+        assert [k[0] for k in kids] == want
+        assert all(b[1] <= k[1] and k[2] <= b[2] for k in kids)
+        assert all(a[2] <= c[1] for a, c in zip(kids, kids[1:]))
+        covered = sum(k[2] - k[1] for k in kids)
+        assert covered >= 0.95 * (b[2] - b[1])
+
+    def parent_of(e):
+        outer = [p for p in prof if p is not e and p[1] <= e[1]
+                 and e[2] <= p[2]]
+        return min(outer, key=lambda p: p[2] - p[1])[0] if outer else None
+
+    recs = _events(path, kind="span")
+    by_id = {r["id"]: r for r in recs}
+    from collections import Counter
+    got = Counter((r["name"], by_id[r["parent"]]["name"]
+                   if r["parent"] is not None else None) for r in recs)
+    assert got == Counter((e[0], parent_of(e)) for e in prof)
+    assert len(recs) == len(prof) == 1 + 2 + 2 * len(OUTER_STEPS) - 1
 
 
 def test_distributed_embed_recorder_with_prefetch(tmp_path):
