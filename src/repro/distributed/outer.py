@@ -21,11 +21,23 @@ strategy-dispatched via ``cfg.selector`` — uniform / rls / kpp,
 ``repro.approx.selectors``), never win a medoid/merge argmin, and never
 count in the cost, so a P∤(N/B) distributed fit reproduces the single-host
 cardinalities and Eq.12 alphas exactly.
+
+Every step of a batch that touches the rows on the device is one compiled
+program, built once and reused by every later batch, fit and instance: the
+diag and landmark gather (``_diag_program``), Eq.8 (``_init_program``),
+the inner loop (``inner._mesh_program``) and Eq.7 + Eq.12
+(``_merge_program``, one program for a first batch and one after it).
+Arrays are operands, never captured, and the static arguments are the mesh,
+the row axes, the ``KernelSpec`` and ``first`` alone; so a program is keyed
+on the batch's shapes (C among them), those, and the caller's matmul
+precision, never on the fit's seed or ``MiniBatchConfig``. Between them the
+host draws the landmarks and, in a first batch, the k-means++ seeds.
 """
 from __future__ import annotations
 
 import math
 import time
+from functools import partial
 from typing import Iterable, NamedTuple, Optional
 
 import jax
@@ -35,6 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.engine import resolve_engine
 from repro.core.init import kmeans_pp_indices
+from repro.core.kernels import KernelSpec
 from repro.core.kkmeans import BIG
 from repro.core.landmarks import (choose_landmarks, num_landmarks,
                                   select_landmark_indices)
@@ -51,7 +64,7 @@ from .mesh import auto_axes, ghost_row_ids
 Array = jax.Array
 
 
-def _dist_argmin_rows(mesh: Mesh, row_axes, score: Array, n_local: int):
+def _dist_argmin_rows(mesh: Mesh, row_axes, score: Array) -> Array:
     """argmin over the (row-sharded) axis 0 of ``score`` [n, C] -> [C] global
     row indices. Local argmin then a gather+min over shards (the paper's
     allreduce-min with index payload)."""
@@ -70,6 +83,78 @@ def _dist_argmin_rows(mesh: Mesh, row_axes, score: Array, n_local: int):
     return shard_map(
         shard_fn, mesh=mesh, in_specs=P(row_axes, None), out_specs=P(),
         check_vma=False)(score)
+
+
+# The batch step's programs (module docstring): static arguments are the
+# mesh, the row axes, the kernel and ``first`` only.
+_STATIC = ("mesh", "spec", "row_axes")
+
+
+@partial(jax.jit, static_argnames=_STATIC)
+def _diag_program(x, l_idx, *, mesh: Mesh, spec: KernelSpec, row_axes):
+    """K(x_i, x_i) of the row-sharded batch, and its landmark rows [L, d]."""
+    diag = shard_map(spec.diag, mesh=mesh, in_specs=P(row_axes, None),
+                     out_specs=P(row_axes), check_vma=False)(x)
+    return diag, jnp.take(x, l_idx, axis=0)
+
+
+@partial(jax.jit, static_argnames=_STATIC)
+def _init_program(x, diag, medoids, mdiag, *, mesh: Mesh, spec: KernelSpec,
+                  row_axes):
+    """Eq.8 on the mesh: labels u0 against the C medoids, and the
+    row-sharded K~^i [n, C] the Eq.12 merge reuses."""
+
+    def shard_fn(x_local, diag_local, medoids, mdiag):
+        kt = spec(x_local, medoids).astype(jnp.float32)
+        d2 = diag_local.astype(jnp.float32)[:, None] + mdiag[None, :] \
+            - 2.0 * kt
+        return jnp.argmin(d2, axis=1).astype(jnp.int32), kt
+
+    return shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(P(row_axes, None), P(row_axes), P(), P()),
+        out_specs=(P(row_axes), P(row_axes, None)),
+        check_vma=False)(x, diag, medoids, mdiag)
+
+
+@partial(jax.jit, static_argnames=_STATIC + ("first",))
+def _merge_program(x, diag, f, counts, k_tilde, wgt, state: GlobalState, *,
+                   mesh: Mesh, spec: KernelSpec, row_axes, first: bool):
+    """Eq.7 batch medoids + Eq.12 merge -> (new state, displacement [C]).
+    A first batch's medoids are its batch medoids, and ``state`` and
+    ``k_tilde`` go unread."""
+    ghost = (1.0 - wgt)[:, None] * BIG                        # sharded
+    # Eq.7: batch medoid scores.
+    score7 = diag.astype(jnp.float32)[:, None] - 2.0 * f + ghost
+    batch_medoids = jnp.take(x, _dist_argmin_rows(mesh, row_axes, score7),
+                             axis=0)                          # replicated
+    if first:
+        return (GlobalState(batch_medoids, spec.diag(batch_medoids), counts,
+                            jnp.array(1, jnp.int32)),
+                jnp.zeros(counts.shape, jnp.float32))
+    alpha = counts / jnp.maximum(counts + state.cardinalities, 1.0)
+
+    def score_fn(x_local, diag_local, kt_local, batch_medoids, alpha):
+        kxm = spec(x_local, batch_medoids).astype(jnp.float32)
+        return (diag_local.astype(jnp.float32)[:, None]
+                - 2.0 * (1.0 - alpha)[None, :] * kt_local
+                - 2.0 * alpha[None, :] * kxm)
+
+    score12 = shard_map(
+        score_fn, mesh=mesh,
+        in_specs=(P(row_axes, None), P(row_axes), P(row_axes, None), P(),
+                  P()),
+        out_specs=P(row_axes, None), check_vma=False)(
+            x, diag, k_tilde, batch_medoids, alpha) + ghost
+    merged = jnp.take(x, _dist_argmin_rows(mesh, row_axes, score12), axis=0)
+    keep = counts == 0
+    medoids = jnp.where(keep[:, None], state.medoids, merged)
+    mdiag = jnp.where(keep, state.medoid_diag, spec.diag(merged))
+    cross = jax.vmap(lambda a, b: spec(a[None], b[None])[0, 0])(
+        medoids, state.medoids)
+    disp = jnp.maximum(mdiag + state.medoid_diag - 2.0 * cross, 0.0)
+    return (GlobalState(medoids, mdiag, state.cardinalities + counts,
+                        state.batches_done + 1), disp)
 
 
 class DistributedMiniBatchKMeans:
@@ -181,75 +266,19 @@ class DistributedMiniBatchKMeans:
             l_idx = choose_landmarks(key, n + n_pad, n_l)
         return l_idx, n_l
 
-    def _init_labels(self, x: Array, diag: Array, medoids: Array,
-                     mdiag: Array):
-        """Eq.8 on the mesh; also returns row-sharded K~^i for the merge."""
-        spec = self.cfg.kernel
-
-        def shard_fn(x_local, diag_local):
-            kt = spec(x_local, medoids).astype(jnp.float32)
-            d2 = diag_local.astype(jnp.float32)[:, None] + mdiag[None, :] \
-                - 2.0 * kt
-            return jnp.argmin(d2, axis=1).astype(jnp.int32), kt
-
-        return shard_map(
-            shard_fn, mesh=self.mesh,
-            in_specs=(P(self.row_axes, None), P(self.row_axes)),
-            out_specs=(P(self.row_axes), P(self.row_axes, None)),
-            check_vma=False)(x, diag)
-
     def _medoid_merge(self, x: Array, diag: Array, res, k_tilde, state,
                       first: bool, wgt: Array):
-        """Eq.7 batch medoids + Eq.12 merge, both via distributed argmin.
+        """Eq.7 batch medoids + Eq.12 merge, both via distributed argmin
+        (``_merge_program``).
 
         ``wgt`` is 0 on ghost rows: masking them out of both argmins keeps
         the selected row *indices* identical to the single-host run (a
         ghost duplicate would otherwise be able to win a tie at a higher
         index).
         """
-        spec, C = self.cfg.kernel, self.cfg.n_clusters
-        ghost = (1.0 - wgt)[:, None] * BIG                        # sharded
-        # Eq.7: batch medoid scores.
-        score7 = diag.astype(jnp.float32)[:, None] - 2.0 * res.f + ghost
-        m_idx = _dist_argmin_rows(self.mesh, self.row_axes, score7,
-                                  x.shape[0] // self.d_size)
-        batch_medoids = jnp.take(x, m_idx, axis=0)                # replicated
-        if first:
-            medoids = batch_medoids
-            mdiag = spec.diag(batch_medoids)
-            cards = res.counts
-            disp = jnp.zeros((C,), jnp.float32)
-        else:
-            alpha = res.counts / jnp.maximum(res.counts + state.cardinalities,
-                                             1.0)
-
-            def score_fn(x_local, diag_local, kt_local):
-                kxm = spec(x_local, batch_medoids).astype(jnp.float32)
-                return (diag_local.astype(jnp.float32)[:, None]
-                        - 2.0 * (1.0 - alpha)[None, :] * kt_local
-                        - 2.0 * alpha[None, :] * kxm)
-
-            score12 = shard_map(
-                score_fn, mesh=self.mesh,
-                in_specs=(P(self.row_axes, None), P(self.row_axes),
-                          P(self.row_axes, None)),
-                out_specs=P(self.row_axes, None), check_vma=False)(
-                    x, diag, k_tilde) + ghost
-            merge_idx = _dist_argmin_rows(self.mesh, self.row_axes, score12,
-                                          x.shape[0] // self.d_size)
-            merged = jnp.take(x, merge_idx, axis=0)
-            keep = (res.counts == 0)[:, None]
-            medoids = jnp.where(keep, state.medoids, merged)
-            mdiag = jnp.where(keep[:, 0], state.medoid_diag, spec.diag(merged))
-            cross = jax.vmap(lambda a, b: spec(a[None], b[None])[0, 0])(
-                medoids, state.medoids)
-            disp = jnp.maximum(mdiag + state.medoid_diag - 2.0 * cross, 0.0)
-            cards = state.cardinalities + res.counts
-        new_state = GlobalState(
-            medoids=medoids, medoid_diag=mdiag, cardinalities=cards,
-            batches_done=(state.batches_done + 1) if not first
-            else jnp.array(1, jnp.int32))
-        return new_state, disp
+        return _merge_program(x, diag, res.f, res.counts, k_tilde, wgt, state,
+                              mesh=self.mesh, spec=self.cfg.kernel,
+                              row_axes=self.row_axes, first=first)
 
     # -- driver -------------------------------------------------------------
 
@@ -320,12 +349,9 @@ class DistributedMiniBatchKMeans:
             # landmark selection over the UNPADDED rows (ghost-bias fix;
             # see _choose_landmarks).
             l_idx, n_l = self._choose_landmarks(k_lm, unpadded, len(idx))
+        progs = dict(mesh=self.mesh, spec=spec, row_axes=self.row_axes)
         with span("outer/diag", rec, **tags):
-            diag = shard_map(
-                lambda xl: spec.diag(xl), mesh=self.mesh,
-                in_specs=P(self.row_axes, None), out_specs=P(self.row_axes),
-                check_vma=False)(x)
-            landmarks = jnp.take(x, l_idx, axis=0)   # [L, d] replicated
+            diag, landmarks = _diag_program(x, l_idx, **progs)
 
         first = state is None
         if first:
@@ -339,15 +365,13 @@ class DistributedMiniBatchKMeans:
                 seed_x = jnp.take(landmarks, seeds, axis=0)
         with span("outer/init", rec, **tags):
             if first:
-                u0, k_tilde = self._init_labels(x, diag, seed_x,
-                                                spec.diag(seed_x))
                 state_in = GlobalState(seed_x, spec.diag(seed_x),
                                        jnp.zeros((cfg.n_clusters,)),
                                        jnp.array(0, jnp.int32))
             else:
-                u0, k_tilde = self._init_labels(x, diag, state.medoids,
-                                                state.medoid_diag)
                 state_in = state
+            u0, k_tilde = _init_program(x, diag, state_in.medoids,
+                                        state_in.medoid_diag, **progs)
 
         with span("outer/inner", rec, **tags):
             res = distributed_kkmeans_fit(

@@ -379,6 +379,59 @@ def test_distributed_exact_resume_bit_identical():
 
 
 @pytest.mark.slow
+def test_outer_batch_programs_compile_once_across_fits():
+    """The outer loop's diag, Eq.8 init and Eq.7/Eq.12 merge programs are
+    keyed on shapes, mesh, kernel and ``first``, never on the fit: a second
+    two-batch fit on the same shapes, by a new instance with another seed,
+    adds no entry to their caches, and its diag/init/merge spans lower
+    nothing. Guards against a static argument that changes per fit."""
+    res = _run_subprocess("""
+        import tempfile
+        from repro.core import KernelSpec, MiniBatchConfig
+        from repro.data.sampling import split_batches
+        from repro.distributed import outer
+        from repro.obs import JsonlRecorder
+
+        rng = np.random.default_rng(4)
+        x = rng.normal(size=(1027, 6)).astype(np.float32)  # ghost rows
+        batches = split_batches(x, 2, strategy="stride")
+        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        progs = (outer._diag_program, outer._init_program,
+                 outer._merge_program)
+
+        def fit(seed, rec=None):
+            cfg = MiniBatchConfig(n_clusters=4, n_batches=2, s=0.5,
+                                  kernel=KernelSpec("rbf", gamma=0.5),
+                                  max_inner_iters=3, seed=seed,
+                                  landmark_multiple_of=8)
+            return outer.DistributedMiniBatchKMeans(
+                mesh, cfg, recorder=rec).fit(batches)
+
+        first = fit(1)
+        sizes = [p._cache_size() for p in progs]
+        path = os.path.join(tempfile.mkdtemp(), "fit.jsonl")
+        with JsonlRecorder(path) as rec:
+            second = fit(2, rec)
+        lowerings = {}
+        for line in open(path):
+            r = json.loads(line)
+            if r.get("kind") == "span" and r["name"] in (
+                    "outer/diag", "outer/init", "outer/merge"):
+                lowerings.setdefault(r["name"], []).append(r["lowerings"])
+        differ = bool((np.asarray(first.state.medoids)
+                       != np.asarray(second.state.medoids)).any())
+        print(json.dumps({"before": sizes, "differ": differ,
+                          "after": [p._cache_size() for p in progs],
+                          "lowerings": lowerings}))
+    """)
+    assert res["differ"], "the two seeds fitted the same medoids"
+    assert min(res["before"]) >= 1 and res["before"][2] == 2
+    assert res["after"] == res["before"]
+    assert res["lowerings"] == {"outer/diag": [0, 0], "outer/init": [0, 0],
+                                "outer/merge": [0, 0]}
+
+
+@pytest.mark.slow
 def test_streaming_sharded_csr_end_to_end():
     """Acceptance: RCV1-scale synthetic stream (d >= 40k) through the full
     pipeline — ragged CSR chunks -> stream_blocks -> prefetch staging ->
